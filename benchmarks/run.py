@@ -6,8 +6,18 @@ dedicated run: ``python -m benchmarks.serve_slo``). The roofline table
 needs dry-run records under results/ and is opt-in via ``--roofline``.
 """
 import argparse
+import os
 import sys
 import traceback
+
+#: on a CPU host the BSP benches need a mesh of virtual devices; the flag
+#: only shapes the host platform, so it is harmless on an accelerator. It
+#: must be set before jax is imported — the modules are imported in main().
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                 ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        [os.environ.get("XLA_FLAGS", ""),
+         "--xla_force_host_platform_device_count=8"]).strip()
 
 
 def main(argv=None) -> None:
@@ -16,6 +26,8 @@ def main(argv=None) -> None:
                     help="include the roofline table (reads dry-run records "
                          "under results/; skipped by default)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}")
     from . import (bsp_throughput, data_plane_bench, kernels_bench,
                    query_throughput, roofline, sa_throughput, segments_bench,
                    serve_slo, sparse_bench, supersteps, table1_example,

@@ -1,21 +1,24 @@
 """Headline claim (C4): BSP synchronisation cost S(p).
 
-Measured supersteps on a real 8-shard run + the analytic cost model for
-p up to 2^20, against the paper's O(log log p) and Kärkkäinen et al.'s
-O(log² p) baselines. The per-round superstep constant is the measured one
-(SM1=11, SM2=9, base=1), which `tests/core/test_bsp.py` pins against
+Measured supersteps on a real run over up to 8 devices + the analytic cost
+model for p up to 2^20, against the paper's O(log log p) and Kärkkäinen et
+al.'s O(log² p) baselines. The per-round superstep constant is the measured
+one (SM1=11, SM2=9, base=1), which `tests/core/test_bsp.py` pins against
 `repro.bsp.suffix_array.estimate_costs` — the exact-replay model for
 realistic (n, p). The capped model below trades that exactness for
-feasibility at astronomic sizes (difference covers clamped at v=2048)."""
-import os
-import subprocess
-import sys
-import textwrap
+feasibility at astronomic sizes (difference covers clamped at v=2048).
 
+The measured run uses the devices this process sees (on a CPU host set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax is
+imported; `benchmarks/run.py` does) and fails with fewer than two."""
+import jax
 import numpy as np
 
+from repro.api import SAOptions, build_suffix_array
+from repro.bsp.counters import BSPCounters
 from repro.core.difference_cover import difference_cover
 from repro.core.seq_ref import accelerated_next_v, fixed_next_v
+from repro.launch.mesh import make_sa_mesh
 
 from .bench_util import emit
 
@@ -35,32 +38,20 @@ def model_supersteps(n, p, schedule):
 
 
 def measured():
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    code = textwrap.dedent("""
-    import numpy as np
-    from repro.api import SAOptions, build_suffix_array
-    from repro.bsp.counters import BSPCounters
-    from repro.launch.mesh import make_sa_mesh
-    rng = np.random.default_rng(0)
-    x = rng.integers(0, 2, size=4096)
+    p = min(8, len(jax.devices()))
+    if p < 2:
+        raise RuntimeError(
+            "supersteps needs at least 2 devices for its BSP run (on a CPU "
+            "host set XLA_FLAGS=--xla_force_host_platform_device_count=8 "
+            "before jax is imported)")
+    x = np.random.default_rng(0).integers(0, 2, size=4096)
     ct = BSPCounters()
-    build_suffix_array(x, SAOptions(mesh=make_sa_mesh(8), base_threshold=64,
+    build_suffix_array(x, SAOptions(mesh=make_sa_mesh(p), base_threshold=64,
                                     counters=ct))
-    print(f"RESULT S={ct.supersteps} H={ct.comm_words} W={ct.work}")
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = src
-    try:
-        r = subprocess.run([sys.executable, "-c", code], env=env, timeout=600,
-                           capture_output=True, text=True)
-        for line in r.stdout.splitlines():
-            if line.startswith("RESULT"):
-                emit("supersteps/measured_p8_n4096", 0.0,
-                     line.replace("RESULT ", "").replace(" ", ";"))
-    except Exception as e:  # pragma: no cover
-        emit("supersteps/measured_p8_n4096", 0.0, f"error={e}")
+    if ct.rounds < 1:
+        raise RuntimeError(f"BSP run over p={p} logged no distributed round")
+    emit(f"supersteps/measured_p{p}_n4096", 0.0,
+         f"S={ct.supersteps};H={ct.comm_words};W={ct.work}")
 
 
 def main():

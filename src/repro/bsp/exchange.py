@@ -17,6 +17,12 @@ slackness n ≥ p³ (§5, Algorithm 2). Exactly 2 supersteps: the overflow flag
 is computed *locally* (no extra collective), so the superstep count per
 exchange really is 2 and `BSPCounters` accounting matches execution.
 
+Every step builds its buffers by gathers (a binary search over a running
+count per group), never by a scatter — XLA lowers an unsorted scatter on a
+TPU through a sort — and the receiver restores each source's order from
+the per-(intermediate, source) counts it receives. Rows travel as columns
+int32[W, m] (see `repro.bsp.primitives`).
+
 Overflow contract
 -----------------
 `exchange` returns a shard-local `overflowed` flag covering every way a cap
@@ -44,9 +50,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .primitives import within_group_index
-
-INT32_MAX = jnp.iinfo(jnp.int32).max
+from .primitives import group_running_counts, lower_bound, take_cols
 
 
 def hop_caps(m: int, p: int, cap_out: int) -> tuple[int, int]:
@@ -55,8 +59,28 @@ def hop_caps(m: int, p: int, cap_out: int) -> tuple[int, int]:
     return cap1, cap2
 
 
+def _fill_slots(cols, group, valid, n_groups: int, cap: int):
+    """buf[:, g, t] = the t-th valid row of `group` g (row order), -1 past
+    the group's end: int32[W, n_groups, cap]; plus the group sizes.
+
+    A gather, not a scatter: each slot finds its row by a binary search
+    over the groups' running counts. (XLA lowers an unsorted scatter on a
+    TPU through a sort, whose compile takes minutes at these sizes.)"""
+    m = group.shape[0]
+    running = group_running_counts(group, valid, n_groups)
+    ends = running[m - 1::m]                                   # [n_groups]
+    sizes = ends - jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    t = jnp.arange(cap, dtype=jnp.int32)
+    g = jnp.arange(n_groups, dtype=jnp.int32)[:, None]
+    at = lower_bound(running, ((ends - sizes)[:, None] + t + 1).reshape(-1))
+    src = jnp.clip(at - (g * m).reshape(-1).repeat(cap), 0, m - 1)
+    filled = (t < sizes[:, None]).reshape(-1)
+    buf = jnp.where(filled[None], take_cols(cols, src), -1)
+    return buf.reshape(cols.shape[0], n_groups, cap), sizes
+
+
 def exchange(
-    rows: jnp.ndarray,       # int32[m, W] (local)
+    cols: jnp.ndarray,       # int32[W, m] (local rows as columns)
     dest: jnp.ndarray,       # int32[m] ∈ [0, p)
     valid: jnp.ndarray,      # bool[m]
     *,
@@ -64,47 +88,63 @@ def exchange(
     cap_out: int,
     axis: str,
 ):
-    """Route valid rows to their dest shards.
+    """Route valid rows to their dest shards, preserving order.
 
-    Returns (out_rows int32[cap_out, W], out_valid bool[cap_out],
-    overflowed bool[]) — rows arrive grouped by source shard then round-robin
-    order; callers re-sort locally. `overflowed` is this shard's local OR
-    that any capacity was exceeded; callers MUST return it through
-    out_specs P(axis) and raise on `any()` (see the module docstring —
-    a set flag is a caller bug, never a recoverable condition).
+    Returns (out int32[W, cap_out], out_valid bool[cap_out],
+    overflowed bool[]). A shard receives its rows as a prefix, grouped by
+    source shard in shard order, each source's rows in their original
+    order — so rows that were globally sorted and are sent to contiguous
+    ranges arrive sorted. `overflowed` is this shard's local OR that any
+    capacity was exceeded; callers MUST return it through out_specs
+    P(axis) and raise on `any()` (see the module docstring — a set flag is
+    a caller bug, never a recoverable condition).
     """
-    m, W = rows.shape
+    W, m = cols.shape
     cap1, cap2 = hop_caps(m, p, cap_out)
+    me = jax.lax.axis_index(axis).astype(jnp.int32)
+    dest = dest.astype(jnp.int32)
 
-    # ---- hop 1: per-destination round robin ----
-    i_d = within_group_index(dest, valid)
-    inter = jnp.where(valid, i_d % p, p)                 # p → dropped
-    slot1 = within_group_index(inter, valid)
-    over1 = jnp.any(valid & (slot1 >= cap1))
-    buf1 = jnp.full((p, cap1, W + 1), -1, dtype=jnp.int32)
-    payload1 = jnp.concatenate([dest[:, None].astype(jnp.int32), rows], axis=1)
-    buf1 = buf1.at[inter, slot1].set(payload1, mode="drop")
-    recv1 = jax.lax.all_to_all(buf1, axis, split_axis=0, concat_axis=0,
-                               tiled=False)
-    flat1 = recv1.reshape(p * cap1, W + 1)
-    dest2 = flat1[:, 0]
-    valid2 = dest2 >= 0
+    # ---- hop 1: the i-th row bound for d goes to intermediate i mod p ----
+    running = group_running_counts(dest, valid, p)
+    i_d = running[jnp.clip(dest, 0, p - 1) * m
+                  + jnp.arange(m, dtype=jnp.int32)] - 1
+    i_d = i_d - jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                 running[m - 1:-1:m]])[jnp.clip(dest, 0,
+                                                                p - 1)]
+    tag = jnp.where(valid, me * p + dest, -1)          # (source, dest)
+    buf1, sizes1 = _fill_slots(jnp.concatenate([tag[None], cols]),
+                               i_d % p, valid, p, cap1)
+    recv1 = jax.lax.all_to_all(buf1, axis, split_axis=1, concat_axis=1,
+                               tiled=False)            # [W+1, source, cap1]
+    flat1 = recv1.reshape(W + 1, p * cap1)
 
-    # ---- hop 2: forward to true destination ----
-    slot2 = within_group_index(dest2, valid2)
-    over2 = jnp.any(valid2 & (slot2 >= cap2))
-    d2 = jnp.where(valid2, dest2, p)
-    buf2 = jnp.full((p, cap2, W + 1), -1, dtype=jnp.int32)
-    buf2 = buf2.at[d2, slot2].set(flat1, mode="drop")
-    recv2 = jax.lax.all_to_all(buf2, axis, split_axis=0, concat_axis=0,
-                               tiled=False)
-    flat2 = recv2.reshape(p * cap2, W + 1)
-    got = flat2[:, 0] >= 0
+    # ---- hop 2: forward to the true destination ----
+    valid2 = flat1[0] >= 0
+    buf2, sizes2 = _fill_slots(flat1, flat1[0] % p, valid2, p, cap2)
+    recv2 = jax.lax.all_to_all(buf2, axis, split_axis=1, concat_axis=1,
+                               tiled=False)            # [W+1, inter, cap2]
+    flat2 = recv2.reshape(W + 1, p * cap2)
 
-    # compact to cap_out
-    order = jnp.argsort(~got, stable=True)
-    out = flat2[order][:cap_out, 1:]
-    out_valid = got[order][:cap_out]
-    over3 = jnp.sum(got.astype(jnp.int32)) > cap_out
-    overflowed = over1 | over2 | over3
+    # ---- restore (source, original order) ----
+    # Block q holds, source by source, the rows whose index i among the
+    # source's rows for this shard has i mod p == q, in order; so row i of
+    # source s sits at q·cap2 + (rows of sources < s in block q) + i // p.
+    src = jnp.where(flat2[0] >= 0, flat2[0] // p, p).reshape(p, cap2)
+    per = jnp.stack([jnp.sum((src == s).astype(jnp.int32), axis=1)
+                     for s in range(p)], axis=1)                 # [q, s]
+    start_in_block = (jnp.cumsum(per, axis=1) - per).reshape(-1)
+    from_src = jnp.sum(per, axis=0)                              # [s]
+    first = jnp.cumsum(from_src) - from_src
+    n_got = jnp.sum(from_src)
+    u = jnp.arange(cap_out, dtype=jnp.int32)
+    s_of = sum((first[s] <= u).astype(jnp.int32) for s in range(1, p))
+    i = u - first[s_of]
+    q = i % p
+    at = q * cap2 + start_in_block[q * p + s_of] + i // p
+    out_valid = u < n_got
+    out = jnp.where(out_valid[None, :],
+                    take_cols(flat2[1:], jnp.where(out_valid, at, 0)), -1)
+
+    overflowed = (jnp.any(sizes1 > cap1) | jnp.any(sizes2 > cap2)
+                  | (n_got > cap_out))
     return out, out_valid, overflowed

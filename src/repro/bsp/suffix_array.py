@@ -16,9 +16,9 @@ Round structure (per recursion level i, modulus v = v_i, cover D = D_i):
 The shard-local sorts inside both psorts are pluggable (`sort_impl`,
 resolved by `repro.bsp.psort.resolve_bsp_sort_impl`): the default "radix"
 path packs the SM1 super-character windows AND the SM2 Lemma-1 payload
-characters into 30-bit int32 key lanes and key-sorts them with ONE variadic
-lax.sort per call (Lemma-1 comparisons only run on equal-window runs, via a
-cond-gated bitonic pass); "lax" is the same two-phase sort on unpacked
+characters into 30-bit int32 key lanes and key-sorts them with ONE key sort
+per call (`repro.core.lsd_sort.lsd_argsort`; Lemma-1 comparisons only run
+on equal-window runs, via a cond-gated bitonic pass); "lax" is the same two-phase sort on unpacked
 columns; "bitonic" is the legacy full comparator network, kept as the
 regression row of `benchmarks/bsp_throughput.py`.
 
@@ -43,17 +43,17 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.difference_cover import cover_tables
 from ..core.dcv_jax import suffix_array_jax
 from ..core.seq_ref import accelerated_next_v
 from .counters import BSPCounters, NULL_COUNTERS
 from .exchange import exchange
 from .psort import (make_local_sort_bitonic, make_local_sort_keyed,
-                    make_pad_rows, make_payload_lt, pack_key_columns,
+                    make_payload_lt, pack_key_columns,
                     packed_width, psort_shard_body, quantize_sigma,
                     resolve_bsp_sort_impl)
 
@@ -84,6 +84,13 @@ def pack_window_columns(win: jnp.ndarray, sigma: int):
     return pack_key_columns(win, -1, sigma)
 
 
+def _class_index(tabs) -> jnp.ndarray:
+    """int32[v]: the index of residue k in D, or -1 when k ∉ D."""
+    d_idx = np.full(tabs.v, -1, np.int32)
+    d_idx[list(tabs.D)] = np.arange(len(tabs.D), dtype=np.int32)
+    return jnp.asarray(d_idx)
+
+
 def _sm1_body(xloc, *, p, v, n_loc, m_loc, tabs, axis, sigma=None):
     dsize = len(tabs.D)
     me = jax.lax.axis_index(axis)
@@ -98,25 +105,24 @@ def _sm1_body(xloc, *, p, v, n_loc, m_loc, tabs, axis, sigma=None):
     off = (D[:, None] + jnp.arange(n_loc // v, dtype=jnp.int32)[None, :] * v
            ).reshape(-1)                                    # [m_loc] local pos
     gpos = me.astype(jnp.int32) * n_loc + off
-    win = xp[off[:, None] + jnp.arange(v, dtype=jnp.int32)[None, :]]
+    win = jnp.stack([xp[off + k] for k in range(v)])        # [v, m_loc]
     if sigma is not None:
         win = pack_window_columns(win, sigma)
-    w = win.shape[1]                       # packed key width ≤ v
+    w = win.shape[0]                       # packed key width ≤ v
     rows = jnp.concatenate([
-        jnp.zeros((m_loc, 1), jnp.int32), win, gpos[:, None]], axis=1)
+        jnp.zeros((1, m_loc), jnp.int32), win, gpos[None]])
 
     # --- Algorithm 2 (key mode) ---
     rows, over = psort_shard_body(rows, p=p, axis=axis)
 
     # --- global dense rank of windows + distinct flag ---
-    keys = rows[:, 1:1 + w]
-    prev_last = jax.lax.ppermute(keys[-1:], axis,
+    keys = rows[1:1 + w]
+    prev_last = jax.lax.ppermute(keys[:, -1], axis,
                                  [(s, s + 1) for s in range(p - 1)])
-    first_b = jnp.where(me == 0, True, jnp.any(keys[0] != prev_last[0]))
-    b = jnp.ones(m_loc, dtype=jnp.int32)
-    b = b.at[0].set(first_b.astype(jnp.int32))
-    if m_loc > 1:
-        b = b.at[1:].set(jnp.any(keys[1:] != keys[:-1], axis=1).astype(jnp.int32))
+    first_b = jnp.where(me == 0, True, jnp.any(keys[:, 0] != prev_last))
+    b = jnp.concatenate([
+        first_b[None], jnp.any(keys[:, 1:] != keys[:, :-1], axis=0)
+    ]).astype(jnp.int32)
     loc_sum = jnp.sum(b)
     sums = jax.lax.all_gather(loc_sum[None], axis).reshape(p)
     offset = (jnp.cumsum(sums) - sums)[me]
@@ -126,26 +132,22 @@ def _sm1_body(xloc, *, p, v, n_loc, m_loc, tabs, axis, sigma=None):
     distinct = jnp.min(b) == 1
 
     # --- route (j, rank) to X' owners; j = block-major sample index ---
-    d_idx = np.full(v, -1, np.int32)
-    for a_i, dd in enumerate(tabs.D):
-        d_idx[dd] = a_i
-    d_idx = jnp.asarray(d_idx)
-    g = rows[:, 1 + w]                                      # gpos
+    d_idx = _class_index(tabs)
+    g = rows[1 + w]                                         # gpos
     j = d_idx[g % v] * ((n_loc // v) * p) + g // v
-    rows2 = jnp.concatenate([
-        jnp.zeros((m_loc, 1), jnp.int32), rank[:, None].astype(jnp.int32),
-        j[:, None]], axis=1)
-    dest = jnp.clip(j // m_loc, 0, p - 1)
     got, got_valid, over2 = exchange(
-        rows2, dest, jnp.ones(m_loc, bool), p=p, cap_out=m_loc, axis=axis)
+        jnp.stack([rank.astype(jnp.int32), j]), jnp.clip(j // m_loc, 0, p - 1),
+        jnp.ones(m_loc, bool), p=p, cap_out=m_loc, axis=axis)
+    # the one data-dependent permutation of the round: ranks arrive in
+    # window order and go to their X' slots
     xprime = jnp.zeros(m_loc, jnp.int32).at[
-        jnp.where(got_valid, got[:, 2] % m_loc, m_loc)
-    ].set(got[:, 1], mode="drop")
+        jnp.where(got_valid, got[1] % m_loc, m_loc)
+    ].set(got[0], mode="drop")
     return xprime, distinct[None], (over | over2)[None]
 
 
 # --------------------------------------------------------------------------
-# SM2: rank scatter + fused Lemma-1 payload sort
+# SM2: rank un-routing + fused Lemma-1 payload sort
 # --------------------------------------------------------------------------
 def _sm2_body(xloc, sa_rank_loc, *, p, v, n_loc, m_loc, tabs, axis,
               impl="bitonic", sigma=None):
@@ -155,62 +157,57 @@ def _sm2_body(xloc, sa_rank_loc, *, p, v, n_loc, m_loc, tabs, axis,
     per_block = (n_loc // v) * p                            # block length in X'
 
     # --- route sample ranks back to position owners ---
+    # Shard s needs, for each a ∈ D, the X' slice of the positions ≡ D[a]
+    # (mod v) it owns: [a·per_block + s·n_loc/v, … + n_loc/v). Those slices
+    # ascend in j and the exchange keeps order, so the ranks arrive as
+    # rank_s[a·(n_loc/v) + o//v] for local position o ≡ D[a] (mod v).
     jloc = me.astype(jnp.int32) * m_loc + jnp.arange(m_loc, dtype=jnp.int32)
     blk = jloc // per_block                                  # index into D
     pos = jnp.asarray(D_np)[jnp.clip(blk, 0, dsize - 1)] + (jloc % per_block) * v
-    rows = jnp.concatenate([
-        jnp.zeros((m_loc, 1), jnp.int32),
-        sa_rank_loc[:, None].astype(jnp.int32), pos[:, None]], axis=1)
     dest = jnp.clip(pos // n_loc, 0, p - 1)
-    got, got_valid, over = exchange(
-        rows, dest, jnp.ones(m_loc, bool), p=p, cap_out=m_loc, axis=axis)
-
-    rank_loc = jnp.full(n_loc + v, -1, jnp.int32).at[
-        jnp.where(got_valid, got[:, 2] % n_loc, n_loc + v)
-    ].set(got[:, 1], mode="drop")
+    got, _, over = exchange(
+        sa_rank_loc[None].astype(jnp.int32), dest, jnp.ones(m_loc, bool),
+        p=p, cap_out=m_loc, axis=axis)
+    o = jnp.arange(n_loc, dtype=jnp.int32)
+    a = _class_index(tabs)[o % v]
+    rank_loc = jnp.where(
+        a >= 0, got[0, jnp.maximum(a, 0) * (n_loc // v) + o // v], -1)
 
     # --- halos: rank (v) and chars (v) from next shard ---
     fwd = jnp.concatenate([rank_loc[:v], xloc[:v]])
     halo = jax.lax.ppermute(fwd, axis, [(s, s - 1) for s in range(1, p)])
     halo = jnp.where(me == p - 1, jnp.full((2 * v,), -1, jnp.int32), halo)
-    rank_loc = rank_loc.at[n_loc:].set(halo[:v])
+    rank_loc = jnp.concatenate([rank_loc, halo[:v]])         # [n_loc + v]
     xp = jnp.concatenate([xloc, halo[v:]])                   # [n_loc + v]
 
     # --- Lemma-1 payloads for ALL local suffixes ---
     offs = jnp.arange(n_loc, dtype=jnp.int32)
     gidx = me.astype(jnp.int32) * n_loc + offs
-    chars = xp[offs[:, None] + jnp.arange(v, dtype=jnp.int32)[None, :]]
+    chars = jnp.stack([xp[offs + k] for k in range(v)])      # [v, n_loc]
     klass = gidx % v
-    shifts = jnp.asarray(tabs.shifts, jnp.int32)             # [v, |D|]
-    rvals = rank_loc[jnp.clip(offs[:, None] + shifts[klass], 0, n_loc + v - 1)]
+    rvals = jnp.stack([                                      # [|D|, n_loc]
+        rank_loc[jnp.clip(offs + jnp.asarray(tabs.shifts[:, k])[klass], 0,
+                          n_loc + v - 1)]
+        for k in range(dsize)])
 
     lam_i1 = jnp.asarray(tabs.lam_idx1, jnp.int32)
     lam_i2 = jnp.asarray(tabs.lam_idx2, jnp.int32)
-    if impl == "bitonic":
-        # legacy: the Lemma-1 comparator at every compare-exchange of the
-        # local bitonic network, raw characters as the head.
-        payload = jnp.concatenate([
-            jnp.zeros((n_loc, 1), jnp.int32), chars, rvals,
-            klass[:, None], gidx[:, None]], axis=1)
-        lt = make_payload_lt(v, v, dsize, lam_i1, lam_i2)
-        out, over2 = psort_shard_body(
-            payload, p=p, axis=axis, lt_fn=lt,
-            local_sort=make_local_sort_bitonic(lt))
-        sa = out[:, 2 + v + dsize]                           # gidx column
-    else:
-        # keyed: pack ("radix") or keep raw ("lax") the character head,
-        # key-sort it, and resolve equal-window runs with the cond-gated
-        # Lemma-1 pass — see repro.bsp.psort.make_local_sort_keyed.
-        keys = pack_key_columns(chars, -1, sigma) if sigma is not None else chars
-        nk = keys.shape[1]
-        payload = jnp.concatenate([
-            jnp.zeros((n_loc, 1), jnp.int32), keys, rvals,
-            klass[:, None], gidx[:, None]], axis=1)
-        lt = make_payload_lt(nk, v, dsize, lam_i1, lam_i2)
-        out, over2 = psort_shard_body(
-            payload, p=p, axis=axis, lt_fn=lt,
-            local_sort=make_local_sort_keyed(nk, v, dsize, lam_i1, lam_i2))
-        sa = out[:, 2 + nk + dsize]                          # gidx column
+    # "bitonic" (legacy): the Lemma-1 comparator at every compare-exchange
+    # of the local bitonic network, raw characters as the head. Keyed: pack
+    # ("radix") or keep raw ("lax") the character head, key-sort it, and
+    # resolve equal-window runs with the cond-gated Lemma-1 pass — see
+    # repro.bsp.psort.make_local_sort_keyed.
+    keys = pack_key_columns(chars, -1, sigma) if sigma is not None else chars
+    nk = keys.shape[0]
+    payload = jnp.concatenate([
+        jnp.zeros((1, n_loc), jnp.int32), keys, rvals, klass[None],
+        gidx[None]])
+    lt = make_payload_lt(nk, v, dsize, lam_i1, lam_i2)
+    local_sort = (make_local_sort_bitonic(lt) if impl == "bitonic" else
+                  make_local_sort_keyed(nk, v, dsize, lam_i1, lam_i2))
+    out, over2 = psort_shard_body(payload, p=p, axis=axis, lt_fn=lt,
+                                  local_sort=local_sort)
+    sa = out[2 + nk + dsize]                                 # gidx field
     return sa, (over | over2)[None]
 
 
@@ -351,7 +348,7 @@ def suffix_array_bsp(
         dsize = len(tabs.D)
         xp_np = np.full(n_pv, -1, dtype=np.int32)
         xp_np[:n] = x_np
-        xg = jax.device_put(jnp.asarray(xp_np), shard)
+        xg = jax.device_put(xp_np, shard)     # one slice per mesh device
 
         # quantized so the data-dependent max collapses onto O(log σ)
         # distinct static-arg values (same packed bit width, no retrace)
@@ -374,7 +371,7 @@ def suffix_array_bsp(
             sa_sub = rec(np.asarray(xprime).reshape(-1), v_next)
             inv = np.empty(m_tot, dtype=np.int32)
             inv[sa_sub] = np.arange(m_tot, dtype=np.int32)
-            sa_rank = jax.device_put(jnp.asarray(inv), shard)
+            sa_rank = jax.device_put(inv, shard)
 
         sa, over = _sm2(xg, sa_rank, p=p, v=v, n_loc=n_loc, m_loc=m_loc,
                         vkey=v, axis=axis, mesh_holder=holder, impl=impl,
@@ -405,13 +402,17 @@ def estimate_costs(
     sort_impl: str = "auto",
     pack_keys: bool = True,
     sigma: int = 256,
+    distinct_round: int | None = None,
 ) -> BSPCounters:
     """Replay `suffix_array_bsp`'s superstep schedule without executing it.
 
     Returns a `BSPCounters` holding the supersteps/communication/work a run
     would log on an input that never triggers the all-distinct recursion
     short-circuit (the worst case — e.g. an all-equal text, for which the
-    replay is *exact*: same labels, same S). `sigma` is the level-0
+    replay is *exact*: same labels, same S). With `distinct_round=r` it
+    replays instead a run whose r-th round (1-based) finds every sample
+    window distinct and so recurses no deeper — what a high-entropy text
+    does once the windows outgrow its repeats. `sigma` is the level-0
     alphabet bound; deeper levels use the dense-rank bound m_tot, so H/W
     are estimates while S and the label sequence are structural.
 
@@ -432,7 +433,7 @@ def estimate_costs(
         ct.superstep("base/gather", h=n, w=n * 4)
         return ct
 
-    def rec(nn: int, vv: int, sig: int) -> None:
+    def rec(nn: int, vv: int, sig: int, depth: int) -> None:
         if nn <= max(base_threshold, 2 * p * vv, 8):
             ct.superstep("base/gather", h=nn, w=nn * 4)
             return
@@ -441,8 +442,9 @@ def estimate_costs(
         dsize = len(tabs.D)
         _, w1, _, nk2 = _sm_widths(vv, quantize_sigma(sig), impl, pack_keys)
         _round_cost("SM1", n_loc, m_loc, p, vv, dsize, w1 + 2, ct)
-        rec(m_tot, schedule(vv, dsize, m_tot), m_tot)
+        if depth != distinct_round:
+            rec(m_tot, schedule(vv, dsize, m_tot), m_tot, depth + 1)
         _round_cost("SM2", n_loc, m_loc, p, vv, dsize, 3 + nk2 + dsize, ct)
 
-    rec(n, max(v, 3), sigma)
+    rec(n, max(v, 3), sigma, 1)
     return ct

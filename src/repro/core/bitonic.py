@@ -39,6 +39,16 @@ def next_pow2(n: int) -> int:
     return p
 
 
+def vary_like(t, *refs):
+    """`t` marked varying over every manual mesh axis that `t` or any of
+    `refs` varies over (a no-op outside shard_map). A loop carry that
+    starts device-invariant (an iota) but is computed from varying data
+    must start with that type, or `fori_loop` rejects the carry."""
+    axes = frozenset().union(*(jax.typeof(r).vma for r in refs))
+    axes = axes - jax.typeof(t).vma
+    return jax.lax.pcast(t, tuple(sorted(axes)), to="varying") if axes else t
+
+
 def bitonic_sort(payload: dict, lt_fn, *, unroll: bool = False) -> dict:
     """Sort `payload` (dict of arrays sharing leading dim N, a power of two)
     ascending by the strict total order `lt_fn(a, b) -> bool[N]`.
@@ -52,6 +62,8 @@ def bitonic_sort(payload: dict, lt_fn, *, unroll: bool = False) -> dict:
     assert n & (n - 1) == 0, f"bitonic_sort needs power-of-two length, got {n}"
     if n <= 1:
         return payload
+    # every stage mixes all leaves: give each the varying axes of all
+    payload = jax.tree_util.tree_map(lambda t: vary_like(t, *leaves), payload)
     schedule = jnp.asarray(_stage_schedule(n))
     idx = jnp.arange(n, dtype=jnp.int32)
 

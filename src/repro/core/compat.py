@@ -1,24 +1,14 @@
-"""Version and platform compatibility shims for the host jax.
+"""Platform-dependent primitive selection.
 
-Two concerns live here:
-
-1. **API layout.** `jax.shard_map` was promoted out of
-   `jax.experimental.shard_map` only in newer jax releases; the baked-in
-   toolchain may predate that. Import `shard_map` from here instead of from
-   jax directly so both layouts work. `check_rep` is disabled on the
-   experimental fallback: the BSP layer's collective patterns (ppermute
-   halos + capacity-bounded all-to-all) are not expressible under its
-   replication checker.
-
-2. **Primitive selection.** The suffix-array hot path is sort-bound, and
-   the best sort primitive differs per platform: XLA's `lax.sort` is a
-   single-threaded comparison sort on CPU (~50× slower than the host
-   radix/introsort at n=200k on this container) but is the native fast path
-   on TPU/GPU, and the Mosaic Pallas kernels in `repro.kernels` only
-   compile on TPU (elsewhere they run in the slow `interpret=True` mode).
-   `default_sort_impl()` / `pallas_available()` encode that decision tree
-   once so `repro.core.dcv_jax` and the `repro.api` registry never
-   hard-code a platform assumption (see docs/architecture.md).
+The suffix-array hot path is sort-bound, and the best sort primitive
+differs per platform: XLA's `lax.sort` is a single-threaded comparison sort
+on CPU (~50× slower than the host radix/introsort at n=200k on a CPU host)
+but is the native fast path on TPU/GPU, and the Mosaic Pallas kernels in
+`repro.kernels` only compile on TPU (elsewhere they run in the slow
+`interpret=True` mode). `default_sort_impl()` / `pallas_available()` encode
+that decision tree once so `repro.core.dcv_jax` and the `repro.api`
+registry never hard-code a platform assumption (see docs/architecture.md).
+A backend that fails to start raises; nothing here falls back to the CPU.
 """
 from __future__ import annotations
 
@@ -26,29 +16,11 @@ import functools
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    @functools.wraps(_shard_map_experimental)
-    def shard_map(f=None, *, mesh, in_specs, out_specs, **kw):
-        kw.setdefault("check_rep", False)
-        if f is None:
-            return functools.partial(_shard_map_experimental, mesh=mesh,
-                                     in_specs=in_specs, out_specs=out_specs,
-                                     **kw)
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, **kw)
-
 
 @functools.lru_cache(maxsize=None)
 def backend_platform() -> str:
     """The default jax backend platform: "cpu", "tpu", or "gpu"."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax failed to init a backend
-        return "cpu"
+    return jax.default_backend()
 
 
 def pallas_available() -> bool:

@@ -27,8 +27,11 @@ correct choice is platform-dependent (see `repro.core.compat`):
             text — the [n, v] window matrix is never materialised), then
             sorted with numpy's introsort (single word) or stable LSD
             passes (multi-word).
-"lax"       XLA's native variadic `lax.sort` (multi-key, same trick the
-            prefix-doubling base case uses) — the accelerator fast path.
+"lax"       XLA's `lax.sort` on the device: the windows are packed into
+            uint32 key lanes and ordered by `lsd_sort.lsd_argsort`
+            (single-operand radix passes, which compile several times
+            faster on a TPU than one multi-operand sort) — the
+            accelerator path.
 "bitonic"   the legacy fully-fused comparator-bitonic network over all n_v
             payload rows (O(n log² n) compare-exchanges). Kept as an
             executable reference and for `benchmarks/sa_throughput.py`
@@ -57,6 +60,7 @@ import numpy as np
 from .bitonic import bitonic_sort, lex_lt_int, next_pow2, sort_rows_with_index
 from .compat import default_sort_impl, pallas_available
 from .difference_cover import cover_tables
+from . import lsd_sort
 from .oracle import suffix_array_doubling
 from .seq_ref import accelerated_next_v
 
@@ -72,8 +76,9 @@ TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
 def trace_events() -> int:
-    """Total number of jax traces performed by this module so far."""
-    return sum(TRACE_COUNTS.values())
+    """Total number of jax traces performed by this module (and the
+    `lsd_sort` argsort it calls) so far."""
+    return sum(TRACE_COUNTS.values()) + sum(lsd_sort.TRACE_COUNTS.values())
 
 
 def resolve_sort_impl(sort_impl: str) -> str:
@@ -200,14 +205,30 @@ def _order_from_words(words):
     return perm, is_start
 
 
-@jax.jit
-def _argsort_cols_lax(cols):
-    """Variadic lax.sort over window columns + index → permutation."""
-    # saca-lint: allow[TRACE001] deliberate: trace-time retrace counter, mutated only while tracing, read by tests via total_traces()
-    TRACE_COUNTS["argsort_cols_lax"] += 1
-    n = cols[0].shape[0]
-    operands = tuple(cols) + (jnp.arange(n, dtype=jnp.int32),)
-    return jax.lax.sort(operands, num_keys=len(cols) + 1)[-1]
+def _window_lanes(xp: np.ndarray, n_v: int, v: int, hi: int):
+    """Pack the v-character windows at positions [0, n_v) into uint32 key
+    lanes for `lsd_sort.lsd_argsort`: (lanes uint32[L, n_v], bits of each
+    lane).
+
+    Text characters map to c + 1 and every pad sentinel (< 0) to 0, packed
+    most-significant-column-first at `bit_length(hi + 1)` bits. For a
+    window that starts in the text this orders exactly like the distinct
+    sentinels do — its first pad column meets a text character (> 0) in
+    any other window, never another pad — so ties are unchanged. Windows
+    that start in the pad region all pack to 0; the caller puts them in
+    their sentinel order (descending position) after the sort."""
+    b = max(1, (hi + 1).bit_length())
+    per = max(1, 32 // b)
+    lanes, bits = [], []
+    for start in range(0, v, per):
+        lane = np.zeros(n_v, np.uint32)
+        for c in range(start, min(start + per, v)):
+            col = xp[c:c + n_v]
+            lane = (lane << np.uint32(b)) | np.where(col >= 0, col + 1, 0
+                                                     ).astype(np.uint32)
+        lanes.append(lane)
+        bits.append(b * (min(start + per, v) - start))
+    return np.stack(lanes), bits
 
 
 def _argsort_rows_pallas(rows: np.ndarray) -> np.ndarray:
@@ -245,8 +266,13 @@ def _window_order(xp: np.ndarray, n_v: int, v: int, lo: int, hi: int,
     if impl == "pallas":
         order = _argsort_rows_pallas(np.stack(cols, axis=1))
     else:
-        order = np.asarray(_argsort_cols_lax(
-            tuple(jnp.asarray(c, jnp.int32) for c in cols))).astype(np.int64)
+        lanes, bits = _window_lanes(xp, n_v, v, hi)
+        order = np.asarray(lsd_sort.lsd_argsort(lanes, bits)).astype(
+            np.int64)
+        # pad-region windows packed to 0 sort first, ascending; their
+        # sentinel order is descending position
+        n_pad = int(np.count_nonzero(cols[0] < 0))
+        order[:n_pad] = order[:n_pad][::-1]
     is_start = np.ones(n_v, dtype=bool)
     if n_v > 1:
         is_start[1:] = _rows_neq(cols, order[1:], order[:-1])

@@ -11,11 +11,12 @@ suffix-array hot path is built from (see docs/architecture.md):
 * `bitonic_stage` / `bitonic_sort` — one compare-exchange stage / a full
   row sort of the fused Lemma-1 payload.
 
-`interpret=True` executes kernel bodies in Python on CPU — the validation
-mode for this container, exercised by `tests/kernels` and the small-n
-cases of `tests/api/test_sort_impl.py`. On TPU pass ``interpret=False``
-for compiled Mosaic (`repro.core.compat.pallas_available` tells you which
-regime the current host is in).
+`interpret` has no default: every caller says which mode it wants.
+``interpret=True`` runs the kernel bodies through the Pallas interpreter —
+the validation mode on CPU, used by `tests/kernels` and the small-n cases
+of `tests/api/test_sort_impl.py`. Program paths pass
+``interpret=not repro.core.compat.pallas_available()``, so on a TPU the
+kernels always run compiled (Mosaic).
 """
 from __future__ import annotations
 
@@ -30,8 +31,8 @@ from .seg_boundary import seg_boundary_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "block", "interpret"))
-def radix_histogram(digits, n_bins: int, block: int = 1024,
-                    interpret: bool = True):
+def radix_histogram(digits, n_bins: int, block: int = 1024, *,
+                    interpret: bool):
     """Global histogram of `digits` (int32[N], values in [0, n_bins)).
 
     Pads N up to a multiple of `block` (the pad digit gets a scratch bin
@@ -51,7 +52,7 @@ def radix_histogram(digits, n_bins: int, block: int = 1024,
 @functools.partial(jax.jit, static_argnames=("num_keys", "block",
                                              "interpret"))
 def dense_rank_sorted(rows, num_keys: int | None = None, block: int = 512,
-                      interpret: bool = True):
+                      *, interpret: bool):
     """Dense ranks of lexicographically sorted rows [N, W]:
     kernel computes block-local boundaries/cumsums, wrapper stitches blocks.
 
@@ -91,7 +92,7 @@ def dense_rank_sorted(rows, num_keys: int | None = None, block: int = 512,
 @functools.partial(jax.jit, static_argnames=("k", "j", "num_keys", "tile",
                                              "interpret"))
 def bitonic_stage(rows, k: int, j: int, num_keys: int | None = None,
-                  tile: int = 256, interpret: bool = True):
+                  tile: int = 256, *, interpret: bool):
     """One bitonic compare-exchange stage (k, j) over rows int32[N, W].
 
     N must be a power of two; rows are compared lexicographically on their
@@ -102,8 +103,8 @@ def bitonic_stage(rows, k: int, j: int, num_keys: int | None = None,
                                 interpret=interpret)
 
 
-def bitonic_sort(rows, num_keys: int | None = None, tile: int = 256,
-                 interpret: bool = True):
+def bitonic_sort(rows, num_keys: int | None = None, tile: int = 256, *,
+                 interpret: bool):
     """Full bitonic row sort: all (k, j) stages of `bitonic_stage` in
     sequence. rows int32[N, W] with N a power of two; sorts ascending by
     the first `num_keys` columns (append a unique index column to make the

@@ -192,8 +192,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
             t2 = time.time()
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, list):   # older jaxlib: one dict per device
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
         rec["lower_s"] = round(t1 - t0, 2)
         rec["compile_s"] = round(t2 - t1, 2)

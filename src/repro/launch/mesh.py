@@ -5,6 +5,15 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_auto_mesh(shape, axes):
+    """`jax.make_mesh` with `Auto` axes: sharding is propagated by the
+    compiler from the in/out shardings, as the model code assumes (jax's
+    default `Explicit` axes would demand an `out_sharding` on every gather
+    such as the embedding lookup)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     boundary, DESIGN §6)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_sa_mesh(p: int | None = None, axis: str = "bsp"):

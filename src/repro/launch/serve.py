@@ -32,6 +32,7 @@ from ..configs import get_config
 from ..models.layers import logits_from_embedding
 from ..models.lm import (decode_step, encode, forward_hidden,
                          init_decode_states, lm_init)
+from .compile_cache import enable_compile_cache
 
 
 def prefill_then_decode(params, cfg, prompts, gen: int, *, enc_out=None,
@@ -58,6 +59,64 @@ def prefill_then_decode(params, cfg, prompts, gen: int, *, enc_out=None,
         out.append(nxt.astype(jnp.int32))
         logits, states = step(params, out[-1], states, jnp.int32(P + g))
     return jnp.concatenate(out, axis=1)
+
+
+def serve_open_loop(index, patterns, cfg, *, seed: int = 0,
+                    query_batch: int | None = None,
+                    offered_qps: float | None = None,
+                    arrival: str | None = None,
+                    coalesce_max_wait_us: float | None = None,
+                    queue_depth: int | None = None,
+                    overload_policy: str | None = None) -> list:
+    """Serve `patterns` against `index` through `repro.serve.SAServer`.
+
+    One seeded open-loop arrival per pattern (process/rate from cfg or the
+    keyword overrides), coalesced into pow2 kernel buckets under admission
+    control. Every kernel shape the patterns' lengths can reach is compiled
+    in an explicit warmup first, so the printed percentiles describe
+    steady state, never JIT time. Returns the `repro.serve.Response` list,
+    one per pattern, in arrival order."""
+    from ..serve import SAServer, make_arrivals, run_open_loop, summarize
+
+    batch = int(query_batch if query_batch is not None else cfg.query_batch)
+    qps = float(offered_qps if offered_qps is not None else cfg.offered_qps)
+    proc = arrival if arrival is not None else cfg.arrival
+    wait_us = float(coalesce_max_wait_us if coalesce_max_wait_us is not None
+                    else cfg.coalesce_max_wait_us)
+    depth = int(queue_depth if queue_depth is not None else cfg.queue_depth)
+    policy = (overload_policy if overload_policy is not None
+              else cfg.overload_policy)
+
+    server = SAServer(index, max_batch=batch,
+                      coalesce_max_wait_us=wait_us, queue_depth=depth,
+                      overload_policy=policy,
+                      gc_hygiene=cfg.gc_hygiene).start()
+    try:
+        t0 = time.time()
+        shapes = server.warmup(pattern_lens=sorted({len(p) for p in patterns}))
+        print(f"warmup: {shapes} kernel shapes compiled in "
+              f"{time.time() - t0:.2f}s (excluded from percentiles)")
+        arrivals = make_arrivals(proc, qps, len(patterns) / qps, seed=seed)
+        t0 = time.time()
+        responses = run_open_loop(server, patterns, arrivals)
+        dt = time.time() - t0
+    finally:
+        server.stop()
+    slo = summarize(responses, dt)
+    m = server.metrics.snapshot()
+    lat = {k: (f"{v * 1e3:.0f}us" if v is not None else "absent")
+           for k, v in [("p50", slo["p50_ms"]), ("p95", slo["p95_ms"]),
+                        ("p99", slo["p99_ms"])]}
+    print(f"served {slo['offered']} open-loop queries ({proc}@{qps:.0f} "
+          f"offered qps) in {dt:.3f}s: ok={slo['ok']} "
+          f"rejected={slo['rejected']} shed={slo['shed']} "
+          f"goodput={slo['goodput_qps']:.0f} qps")
+    print(f"latency p50={lat['p50']} p95={lat['p95']} p99={lat['p99']}; "
+          f"coalesced batch mean={m['batch_size']['mean'] or 0:.1f} "
+          f"occupancy={m['bucket_occupancy']['mean'] or 0:.2f} "
+          f"(policy={policy}, queue_depth={depth}, "
+          f"max_wait={wait_us:.0f}us)")
+    return responses
 
 
 def serve_sa_queries(cfg, *, n_chars: int, n_docs: int, n_queries: int,
@@ -98,7 +157,6 @@ def serve_sa_queries(cfg, *, n_chars: int, n_docs: int, n_queries: int,
                        SuffixArrayIndex, builder_cache_stats,
                        corpus_fingerprint, encode_docs)
     from ..bsp.counters import BSPCounters
-    from ..serve import SAServer, make_arrivals, run_open_loop, summarize
     from .mesh import make_sa_mesh
 
     n_segments = int(segments if segments is not None
@@ -193,31 +251,11 @@ def serve_sa_queries(cfg, *, n_chars: int, n_docs: int, n_queries: int,
         else:
             patterns.append(rng.integers(0, 256, size=pattern_len))
 
-    batch = int(query_batch if query_batch is not None else cfg.query_batch)
-    qps = float(offered_qps if offered_qps is not None else cfg.offered_qps)
-    proc = arrival if arrival is not None else cfg.arrival
-    wait_us = float(coalesce_max_wait_us if coalesce_max_wait_us is not None
-                    else cfg.coalesce_max_wait_us)
-    depth = int(queue_depth if queue_depth is not None else cfg.queue_depth)
-    policy = (overload_policy if overload_policy is not None
-              else cfg.overload_policy)
-
-    server = SAServer(index, max_batch=batch,
-                      coalesce_max_wait_us=wait_us, queue_depth=depth,
-                      overload_policy=policy,
-                      gc_hygiene=cfg.gc_hygiene).start()
-    t0 = time.time()
-    shapes = server.warmup(pattern_lens=(pattern_len,))
-    print(f"warmup: {shapes} kernel shapes compiled in "
-          f"{time.time() - t0:.2f}s (excluded from percentiles)")
-
-    # ~n_queries seeded open-loop arrivals at the offered rate
-    arrivals = make_arrivals(proc, qps, n_queries / qps, seed=seed)
-    t0 = time.time()
-    responses = run_open_loop(server, patterns, arrivals)
-    dt = time.time() - t0
-    server.stop()
-    slo = summarize(responses, dt)
+    responses = serve_open_loop(
+        index, patterns, cfg, seed=seed, query_batch=query_batch,
+        offered_qps=offered_qps, arrival=arrival,
+        coalesce_max_wait_us=coalesce_max_wait_us, queue_depth=queue_depth,
+        overload_policy=overload_policy)
 
     # planted patterns that were admitted must hit; spot-check counts
     # against the closed-loop batched engine (same index, same kernel)
@@ -228,20 +266,6 @@ def serve_sa_queries(cfg, *, n_chars: int, n_docs: int, n_queries: int,
     if check:
         want = index.count_batch([patterns[i % n_queries] for i, _ in check])
         assert [r.count for _, r in check] == list(want), "tier != engine"
-
-    m = server.metrics.snapshot()
-    lat = {k: (f"{v * 1e3:.0f}us" if v is not None else "absent")
-           for k, v in [("p50", slo["p50_ms"]), ("p95", slo["p95_ms"]),
-                        ("p99", slo["p99_ms"])]}
-    print(f"served {slo['offered']} open-loop queries ({proc}@{qps:.0f} "
-          f"offered qps) in {dt:.3f}s: ok={slo['ok']} "
-          f"rejected={slo['rejected']} shed={slo['shed']} "
-          f"goodput={slo['goodput_qps']:.0f} qps")
-    print(f"latency p50={lat['p50']} p95={lat['p95']} p99={lat['p99']}; "
-          f"coalesced batch mean={m['batch_size']['mean'] or 0:.1f} "
-          f"occupancy={m['bucket_occupancy']['mean'] or 0:.2f} "
-          f"(policy={policy}, queue_depth={depth}, "
-          f"max_wait={wait_us:.0f}us)")
     return index
 
 
@@ -284,6 +308,7 @@ def main():
                     help="docs to stream through add_docs after the initial "
                          "build (requires --segments; default: cfg.ingest)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if getattr(cfg, "name", "") == "suffix-array":

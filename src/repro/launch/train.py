@@ -39,6 +39,7 @@ from ..data.pipeline import (GATE_POLICIES, PipelineConfig,
                              TrainingDataPlane, synthetic_corpus,
                              synthetic_doc_shards)
 from ..models.lm import lm_init
+from .compile_cache import enable_compile_cache
 from ..train.optim import OptConfig
 from ..train.train_step import (TrainConfig, make_train_state,
                                 make_train_step)
@@ -123,6 +124,7 @@ def main(argv=None):
     ap.add_argument("--probe-len", type=int, default=64)
     ap.add_argument("--probe-prompt", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

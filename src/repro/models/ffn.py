@@ -8,11 +8,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..bsp.primitives import within_group_index
-from ..core.compat import shard_map
 from .layers import COMPUTE_DTYPE, activation
 
 
@@ -112,7 +112,7 @@ def _moe_local(x, wr, wg, wu, wd, *, cfg, tp: int, axis: str | None):
     owner = ids_f // E_loc
     valid = jnp.ones_like(ids_f, dtype=bool)
     cap = int(cfg.capacity_factor * T * k / tp) + 8
-    slot = within_group_index(owner, valid)
+    slot = within_group_index(owner, valid, tp)
     keep = slot < cap
 
     tok_buf = jnp.zeros((tp, cap, d), COMPUTE_DTYPE)
@@ -131,7 +131,7 @@ def _moe_local(x, wr, wg, wu, wd, *, cfg, tp: int, axis: str | None):
     eid = meta_buf.reshape(R)
     ev = eid >= 0
     cap_e = int(cfg.capacity_factor * T * k * tp / E) + 8
-    eslot = within_group_index(eid, ev)
+    eslot = within_group_index(eid, ev, E_loc)
     ekeep = ev & (eslot < cap_e)
     e_ix = jnp.where(ekeep, eid, E_loc)
     ebuf = jnp.zeros((E_loc, cap_e, d), COMPUTE_DTYPE)
@@ -185,7 +185,7 @@ def _moe_decode_local(x, wr, wg, wu, wd, *, cfg, tp: int, axis: str | None):
     mine = (ids_f // E_loc) == me
     eid = jnp.where(mine, ids_f % E_loc, E_loc)
     cap_e = max(8, int(cfg.capacity_factor * T * k / max(E_loc, 1)) + 8)
-    eslot = within_group_index(eid, mine)
+    eslot = within_group_index(eid, mine, E_loc)
     keep = mine & (eslot < cap_e)
     e_ix = jnp.where(keep, eid, E_loc)
     ebuf = jnp.zeros((E_loc, cap_e, d), COMPUTE_DTYPE)

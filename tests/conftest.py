@@ -5,10 +5,3 @@ import sys
 # own flags in a separate process). Keep threads tame on the 1-core box.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# The image does not ship `hypothesis`; fall back to the deterministic stub
-# in tests/_stubs (real hypothesis wins whenever it is importable, e.g. CI).
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_stubs"))

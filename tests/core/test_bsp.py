@@ -55,7 +55,7 @@ def test_exchange_adversarial_skew():
     import jax, jax.numpy as jnp, numpy as np, functools
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.bsp.exchange import exchange
-    from repro.core.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("bsp",))
     p, m = 8, 32
     rng = np.random.default_rng(1)
@@ -64,9 +64,9 @@ def test_exchange_adversarial_skew():
     rows = np.stack([np.arange(p * m, dtype=np.int32),
                      rng.integers(0, 99, p * m).astype(np.int32)], axis=1)
     def f(r, d):
-        out, valid, over = exchange(r, d[:, 0], jnp.ones(m, bool), p=p,
+        out, valid, over = exchange(r.T, d[:, 0], jnp.ones(m, bool), p=p,
                                     cap_out=p * m, axis="bsp")
-        return out, valid[:, None], over[None]
+        return out.T, valid[:, None], over[None]
     fn = jax.jit(shard_map(f, mesh=mesh,
         in_specs=(P("bsp"), P("bsp")), out_specs=(P("bsp"), P("bsp"), P("bsp"))))
     out, valid, over = fn(jnp.asarray(rows), jnp.asarray(dest[:, None]))
@@ -79,6 +79,29 @@ def test_exchange_adversarial_skew():
     assert pv[3].sum() == p * m            # all rows arrived at shard 3
     assert sorted(per[3][pv[3]][:, 0].tolist()) == list(range(p * m))
     assert pv[[0,1,2,4,5,6,7]].sum() == 0
+
+    # order-preserving delivery: each shard receives its rows as a prefix,
+    # by source shard, each source's rows in their original order
+    for trial in range(3):
+        dest = rng.integers(0, p, p * m).astype(np.int32)
+        keep = rng.random(p * m) < 0.7
+        rows[:, 1] = np.where(keep, 0, -1)
+        def g(r, d):
+            out, valid, over = exchange(r.T, d[:, 0], r[:, 1] == 0, p=p,
+                                        cap_out=2 * m + 2 * p + 4, axis="bsp")
+            return out.T, valid[:, None], over[None]
+        fn = jax.jit(shard_map(g, mesh=mesh, in_specs=(P("bsp"), P("bsp")),
+                               out_specs=(P("bsp"), P("bsp"), P("bsp"))))
+        out, valid, over = fn(jnp.asarray(rows), jnp.asarray(dest[:, None]))
+        assert not bool(np.asarray(over).any())
+        cap = 2 * m + 2 * p + 4
+        per = np.asarray(out).reshape(p, cap, 2)
+        pv = np.asarray(valid).reshape(p, cap)
+        for d in range(p):
+            n_d = int(pv[d].sum())
+            assert pv[d][:n_d].all()
+            want = rows[keep & (dest == d), 0]
+            assert per[d][:n_d, 0].tolist() == want.tolist(), (trial, d)
     print("OK")
     """)
 
@@ -155,7 +178,7 @@ def test_bsp_counters_match_estimate_and_overflow_is_hard_error():
     from repro.bsp.counters import BSPCounters
     from repro.bsp.exchange import exchange
     from repro.bsp.suffix_array import estimate_costs, suffix_array_bsp
-    from repro.core.compat import shard_map
+    from jax import shard_map
     import repro.bsp.psort as psort
 
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("bsp",))
@@ -181,9 +204,9 @@ def test_bsp_counters_match_estimate_and_overflow_is_hard_error():
                      np.arange(p * m, dtype=np.int32)], axis=1)
     dest = np.zeros((p * m, 1), np.int32)          # everything to shard 0
     def f(r, d):
-        out, valid, over = exchange(r, d[:, 0], jnp.ones(m, bool), p=p,
+        out, valid, over = exchange(r.T, d[:, 0], jnp.ones(m, bool), p=p,
                                     cap_out=4, axis="bsp")
-        return out, valid[:, None], over[None]
+        return out.T, valid[:, None], over[None]
     fn = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("bsp"), P("bsp")),
                            out_specs=(P("bsp"), P("bsp"), P("bsp"))))
     _, _, over = fn(jnp.asarray(rows), jnp.asarray(dest))

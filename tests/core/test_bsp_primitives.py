@@ -4,8 +4,9 @@ import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.bsp.exchange import hop_caps
-from repro.bsp.primitives import (counts_per_bucket, lex_lt_rows,
-                                  searchsorted_rows, within_group_index)
+from repro.bsp.primitives import (counts_per_bucket, group_running_counts,
+                                  lower_bound, searchsorted_rows, take_cols,
+                                  within_group_index)
 from repro.bsp.psort import (pack_key_columns, packed_width, quantize_sigma,
                              resolve_bsp_sort_impl)
 from repro.bsp.suffix_array import pack_window_columns
@@ -18,7 +19,7 @@ def test_within_group_index(items):
     group = np.array([g for g, _ in items], np.int32)
     valid = np.array([v for _, v in items], bool)
     out = np.asarray(within_group_index(jnp.asarray(group),
-                                        jnp.asarray(valid)))
+                                        jnp.asarray(valid), 6))
     seen: dict = {}
     for i, (g, v) in enumerate(items):
         if not v:
@@ -51,7 +52,7 @@ def test_pack_window_columns_preserves_order(n, v, seed):
     rng = np.random.default_rng(seed)
     sigma = int(rng.integers(2, 300))
     win = rng.integers(-1, sigma, (n, v)).astype(np.int32)
-    packed = np.asarray(pack_window_columns(jnp.asarray(win), sigma))
+    packed = np.asarray(pack_window_columns(jnp.asarray(win.T), sigma)).T
     # lexicographic order identical before/after packing
     o1 = np.lexsort(tuple(win[:, c] for c in range(v - 1, -1, -1)))
     o2 = np.lexsort(tuple(packed[:, c]
@@ -75,7 +76,7 @@ def test_pack_key_columns_generic_ranges(n, k, seed, lo):
     rng = np.random.default_rng(seed)
     hi = lo + int(rng.integers(1, 500))
     cols = rng.integers(lo, hi + 1, (n, k)).astype(np.int32)
-    packed = np.asarray(pack_key_columns(jnp.asarray(cols), lo, hi))
+    packed = np.asarray(pack_key_columns(jnp.asarray(cols.T), lo, hi)).T
     assert packed.shape == (n, packed_width(k, lo, hi))
     assert packed.max(initial=0) < np.iinfo(np.int32).max
     o1 = np.lexsort(tuple(cols[:, c] for c in range(k - 1, -1, -1)))
@@ -122,7 +123,8 @@ def test_searchsorted_rows_matches_linear(m, q, seed):
     rows = rng.integers(0, 4, (m, W)).astype(np.int32)
     spl = np.sort(rng.integers(0, 4, (q, W)).astype(np.int32), axis=0)
     spl = spl[np.lexsort(tuple(spl[:, c] for c in range(W - 1, -1, -1)))]
-    got = np.asarray(searchsorted_rows(jnp.asarray(spl), jnp.asarray(rows)))
+    got = np.asarray(searchsorted_rows(jnp.asarray(spl.T),
+                                       jnp.asarray(rows.T)))
     for i in range(m):
         want = sum(1 for s in spl if tuple(s) < tuple(rows[i]))
         assert got[i] == want
@@ -133,3 +135,33 @@ def test_counts_per_bucket():
     valid = jnp.asarray([True, True, False, True, True, True])
     out = np.asarray(counts_per_bucket(dest, valid, 4))
     assert out.tolist() == [1, 1, 0, 3]
+
+
+@given(st.lists(st.integers(0, 30), min_size=1, max_size=70),
+       st.lists(st.integers(-2, 33), min_size=1, max_size=20))
+@settings(max_examples=60, deadline=None)
+def test_lower_bound_matches_searchsorted(a, x):
+    a = np.sort(np.asarray(a, np.int32))
+    x = np.asarray(x, np.int32)
+    got = np.asarray(lower_bound(jnp.asarray(a), jnp.asarray(x)))
+    assert got.tolist() == np.searchsorted(a, x, side="left").tolist()
+
+
+@given(st.lists(st.tuples(st.integers(0, 3), st.booleans()), min_size=1,
+                max_size=60))
+@settings(max_examples=40, deadline=None)
+def test_group_running_counts_and_take_cols(items):
+    group = np.array([g for g, _ in items], np.int32)
+    valid = np.array([v for _, v in items], bool)
+    m = len(items)
+    run = np.asarray(group_running_counts(jnp.asarray(group),
+                                          jnp.asarray(valid), 4))
+    for g in range(4):
+        member = (group == g) & valid
+        base = run[g * m - 1] if g else 0
+        assert (run[g * m:(g + 1) * m] - base).tolist() == \
+            np.cumsum(member).tolist()
+    cols = np.stack([np.arange(m), 10 * np.arange(m)]).astype(np.int32)
+    idx = group % m
+    assert np.asarray(take_cols(jnp.asarray(cols), jnp.asarray(idx))
+                      ).tolist() == cols[:, idx].tolist()

@@ -19,7 +19,8 @@ def _sorted_rows(rng, n, W, lo=0, hi=5):
 
 def _assert_seg_parity(rows, block, num_keys=None):
     rows = jnp.asarray(rows)
-    f, c, t = seg_boundary_pallas(rows, num_keys=num_keys, block=block)
+    f, c, t = seg_boundary_pallas(rows, num_keys=num_keys, block=block,
+                                  interpret=True)
     rf, rc, rt = ref.seg_boundary_ref(rows, num_keys=num_keys, block=block)
     np.testing.assert_array_equal(np.asarray(f), np.asarray(rf))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(rc))
@@ -49,7 +50,8 @@ def test_seg_boundary_num_keys_prefix(num_keys):
 def test_seg_boundary_all_equal_rows():
     rows = np.full((1024, 3), 7, np.int32)
     _assert_seg_parity(rows, block=256)
-    f, _c, t = seg_boundary_pallas(jnp.asarray(rows), block=256)
+    f, _c, t = seg_boundary_pallas(jnp.asarray(rows), block=256,
+                                   interpret=True)
     # one boundary per block (block-local convention), nothing else
     assert int(np.asarray(f).sum()) == 1024 // 256
     np.testing.assert_array_equal(np.asarray(t), np.ones(4, np.int32))
@@ -58,14 +60,16 @@ def test_seg_boundary_all_equal_rows():
 def test_seg_boundary_all_distinct_rows():
     rows = np.arange(512, dtype=np.int32)[:, None] * np.ones((1, 2), np.int32)
     _assert_seg_parity(rows, block=128)
-    f, c, t = seg_boundary_pallas(jnp.asarray(rows), block=128)
+    f, c, t = seg_boundary_pallas(jnp.asarray(rows), block=128,
+                                  interpret=True)
     assert int(np.asarray(f).sum()) == 512          # every row a boundary
     np.testing.assert_array_equal(np.asarray(t), np.full(4, 128, np.int32))
 
 
 def _assert_hist_parity(digits, n_bins, block):
     digits = jnp.asarray(digits, jnp.int32)
-    got = np.asarray(radix_histogram_pallas(digits, n_bins, block=block))
+    got = np.asarray(radix_histogram_pallas(digits, n_bins, block=block,
+                                            interpret=True))
     want = np.asarray(ref.radix_histogram_ref(digits, n_bins, block))
     np.testing.assert_array_equal(got, want)
     # blockwise sums must also agree with the global histogram
@@ -96,5 +100,6 @@ def test_radix_histogram_boundary_digits():
 def test_radix_histogram_skewed_blocks():
     # each block holds a single distinct digit: per-block rows are one-hot
     d = np.repeat(np.arange(8, dtype=np.int32), 256)
-    got = np.asarray(radix_histogram_pallas(jnp.asarray(d), 8, block=256))
+    got = np.asarray(radix_histogram_pallas(jnp.asarray(d), 8, block=256,
+                                            interpret=True))
     np.testing.assert_array_equal(got, np.eye(8, dtype=np.int32) * 256)

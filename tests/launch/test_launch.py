@@ -65,7 +65,8 @@ def test_tiny_mesh_lowering_subprocess():
         make_train_step
     from repro.train.optim import OptConfig
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     cfg = get_config("gemma3_1b").smoke().replace(n_layers=6)
     params, _ = lm_init(jax.random.PRNGKey(0), cfg)
     tcfg = TrainConfig(opt=OptConfig())
@@ -79,8 +80,6 @@ def test_tiny_mesh_lowering_subprocess():
     with mesh:
         compiled = jax.jit(step).lower(state, batch).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older jaxlib: one dict per device
-        cost = cost[0] if cost else {}
     assert cost.get("flops", 0) > 0
     print("LOWER_OK", int(cost["flops"]))
     """)
