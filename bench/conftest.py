@@ -1,0 +1,10 @@
+import os
+import sys
+
+# The benchmark's own tests run on the CPU at tiny sizes, with the
+# program (src/) and the benchmark package importable.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
