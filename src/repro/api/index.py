@@ -37,6 +37,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..core.spans import span
 from ..text.lcp import lcp_kasai, repeated_substring_spans
 from .build import build_suffix_array
 from .options import SAOptions
@@ -183,9 +184,10 @@ class SuffixArrayIndex:
             # facade dispatch: a sampled plan builds the sparse subclass
             from ..sparse import SparseSuffixArrayIndex
             return SparseSuffixArrayIndex.build(text, opts, sigma=sigma)
-        text = np.asarray(text, np.int64)
-        sa = build_suffix_array(text, opts)
-        return cls(text, sa, shift=0, options=opts, sigma=sigma)
+        with span("facade"):
+            text = np.asarray(text, np.int64)
+            sa = build_suffix_array(text, opts)
+            return cls(text, sa, shift=0, options=opts, sigma=sigma)
 
     @classmethod
     def from_docs(cls, docs, options: SAOptions | None = None, *,
@@ -197,10 +199,12 @@ class SuffixArrayIndex:
         if opts.sample_rate > 1 and cls is SuffixArrayIndex:
             from ..sparse import SparseSuffixArrayIndex
             return SparseSuffixArrayIndex.from_docs(docs, opts, sigma=sigma)
-        text, starts, n_docs = encode_docs(docs)
-        sa = build_suffix_array(text, opts)
-        return cls(text, sa, doc_starts=starts, shift=n_docs, options=opts,
-                   sigma=sigma)
+        with span("facade"):
+            with span("encode"):
+                text, starts, n_docs = encode_docs(docs)
+            sa = build_suffix_array(text, opts)
+            return cls(text, sa, doc_starts=starts, shift=n_docs,
+                       options=opts, sigma=sigma)
 
     # --------------------------------------------------------- persistence
     def save(self, path: str) -> str:

@@ -46,7 +46,8 @@ Shapes are quantised to a geometric bucket grid (`pad_bucket`) when
 computation; `TRACE_COUNTS` records one event per actual jax trace, which
 the cache tests in `tests/api/test_sort_impl.py` assert against. The
 recursion driver stays in Python (shapes are data-independent functions of
-the schedule).
+the schedule); each level and each of its phases runs under a host span of
+`repro.core.spans`, which the profiler records beside the device.
 """
 from __future__ import annotations
 
@@ -63,6 +64,7 @@ from .difference_cover import cover_tables
 from . import lsd_sort
 from .oracle import suffix_array_doubling
 from .seq_ref import accelerated_next_v
+from .spans import span
 
 INT32_MAX = np.int32(np.iinfo(np.int32).max)
 
@@ -259,23 +261,29 @@ def _window_order(xp: np.ndarray, n_v: int, v: int, lo: int, hi: int,
     `is_start` marks the row-equality run boundaries along `order`.
     """
     if impl == "radix":
-        words = _window_words(xp, n_v, v, lo, hi)
-        order, is_start = _order_from_words(words)
+        with span("dcv.pack"):
+            words = _window_words(xp, n_v, v, lo, hi)
+        with span("dcv.sort"):
+            order, is_start = _order_from_words(words)
         return order, words, is_start
-    cols = [np.ascontiguousarray(xp[c:c + n_v]) for c in range(v)]
-    if impl == "pallas":
-        order = _argsort_rows_pallas(np.stack(cols, axis=1))
-    else:
-        lanes, bits = _window_lanes(xp, n_v, v, hi)
-        order = np.asarray(lsd_sort.lsd_argsort(lanes, bits)).astype(
-            np.int64)
-        # pad-region windows packed to 0 sort first, ascending; their
-        # sentinel order is descending position
-        n_pad = int(np.count_nonzero(cols[0] < 0))
-        order[:n_pad] = order[:n_pad][::-1]
-    is_start = np.ones(n_v, dtype=bool)
-    if n_v > 1:
-        is_start[1:] = _rows_neq(cols, order[1:], order[:-1])
+    with span("dcv.pack"):
+        cols = [np.ascontiguousarray(xp[c:c + n_v]) for c in range(v)]
+        if impl != "pallas":
+            lanes, bits = _window_lanes(xp, n_v, v, hi)
+    with span("dcv.sort"):
+        if impl == "pallas":
+            order = _argsort_rows_pallas(np.stack(cols, axis=1))
+        else:
+            order = np.asarray(lsd_sort.lsd_argsort(lanes, bits)).astype(
+                np.int64)
+            # pad-region windows packed to 0 sort first, ascending; their
+            # sentinel order is descending position
+            n_pad = int(np.count_nonzero(cols[0] < 0))
+            order[:n_pad] = order[:n_pad][::-1]
+    with span("dcv.runs"):
+        is_start = np.ones(n_v, dtype=bool)
+        if n_v > 1:
+            is_start[1:] = _rows_neq(cols, order[1:], order[:-1])
     return order, cols, is_start
 
 
@@ -516,22 +524,23 @@ def _resolve_ties(order, is_start, rank, shifts_np, lam1_np, lam2_np,
     stride = v
     cap = max(_TIEBREAK_COMPACT_MAX, n_v >> 3)
     while U > cap and stride < n_v:
-        sl = np.flatnonzero(unresolved)
-        p = order[sl]
-        nxt = p + stride
-        key = np.where(nxt < n_v, r_pos[np.minimum(nxt, n_v - 1)], -1)
-        packed = (r_pos[p] << 32) | (key + 1)             # both < 2^31
-        local = np.argsort(packed, kind="stable")
-        order[sl] = p[local]
-        pk = packed[local]
-        if len(sl) > 1:
-            # run starts re-emerge via the high bits; interiors refine.
-            is_start[sl[1:]] = pk[1:] != pk[:-1]
-        start_slot, run_id, r_sorted, sizes = run_state(is_start)
-        r_pos[order] = r_sorted
-        unresolved = sizes[run_id] > 1
-        U = int(unresolved.sum())
-        stride *= 2
+        with span("dcv.refine", ties=U):
+            sl = np.flatnonzero(unresolved)
+            p = order[sl]
+            nxt = p + stride
+            key = np.where(nxt < n_v, r_pos[np.minimum(nxt, n_v - 1)], -1)
+            packed = (r_pos[p] << 32) | (key + 1)         # both < 2^31
+            local = np.argsort(packed, kind="stable")
+            order[sl] = p[local]
+            pk = packed[local]
+            if len(sl) > 1:
+                # run starts re-emerge via the high bits; interiors refine.
+                is_start[sl[1:]] = pk[1:] != pk[:-1]
+            start_slot, run_id, r_sorted, sizes = run_state(is_start)
+            r_pos[order] = r_sorted
+            unresolved = sizes[run_id] > 1
+            U = int(unresolved.sum())
+            stride *= 2
     if U == 0:
         return order
 
@@ -543,9 +552,11 @@ def _resolve_ties(order, is_start, rank, shifts_np, lam1_np, lam2_np,
     lane = sl - start_slot[run_id[sl]]
     g2 = next_pow2(int(lane.max()) + 1)
     if g2 <= _HOST_LANE_MAX:
-        rows, row_of = np.unique(run_id[sl], return_inverse=True)
-        order[sl] = _lambda_tiebreak_host(
-            p, lane, row_of, len(rows), g2, rvals, klass, lam1_np, lam2_np)
+        with span("dcv.lemma1", ties=U, width=g2, path="host"):
+            rows, row_of = np.unique(run_id[sl], return_inverse=True)
+            order[sl] = _lambda_tiebreak_host(
+                p, lane, row_of, len(rows), g2, rvals, klass, lam1_np,
+                lam2_np)
         return order
 
     n2 = next_pow2(U)
@@ -557,9 +568,10 @@ def _resolve_ties(order, is_start, rank, shifts_np, lam1_np, lam2_np,
     rv_p[:U] = rvals
     kl_p[:U] = klass
     pos_p[:U] = p
-    out = np.asarray(_lambda_tiebreak_jit(
-        jnp.asarray(seg_p), jnp.asarray(rv_p), jnp.asarray(kl_p),
-        jnp.asarray(pos_p), lam1_jnp, lam2_jnp))
+    with span("dcv.lemma1", ties=U, width=g2, path="device"):
+        out = np.asarray(_lambda_tiebreak_jit(
+            jnp.asarray(seg_p), jnp.asarray(rv_p), jnp.asarray(kl_p),
+            jnp.asarray(pos_p), lam1_jnp, lam2_jnp))
     order[sl] = out[:U]
     return order
 
@@ -603,14 +615,22 @@ def suffix_array_jax(
     if n == 1:
         return np.zeros(1, dtype=np.int32)
 
-    def rec(x_np: np.ndarray, v: int) -> np.ndarray:
+    def rec(x_np: np.ndarray, v: int, level: int = 0) -> np.ndarray:
         n = len(x_np)
         if n <= max(base_threshold, v, 4):
-            return _suffix_array_base(x_np, impl)
+            with span("dcv.base"):
+                return _suffix_array_base(x_np, impl)
         n_b = pad_bucket(n) if bucket else n
         v = int(min(max(v, 3), n_b))
-        tabs = cover_tables(v)
         n_v = v * int(np.ceil(n_b / v))
+        with span("dcv.level", level=level, n_v=n_v, v=v):
+            return rec_level(x_np, v, n_v, level)
+
+    def rec_level(x_np: np.ndarray, v: int, n_v: int,
+                  level: int) -> np.ndarray:
+        """One level of `rec` above its base case."""
+        n = len(x_np)
+        tabs = cover_tables(v)
         # Pad with *distinct, decreasing* negative sentinels. Distinctness
         # matters: equal sentinels would form giant tie groups and defeat
         # the `distinct` recursion short-circuit once bucketing makes the
@@ -618,32 +638,35 @@ def suffix_array_jax(
         # the first differing window column between two real suffixes is
         # never pad-vs-pad (pad values are position-unique), so the
         # sentinels' relative order never decides a real comparison.
-        xp_np = np.empty(n_v + 2 * v, dtype=np.int64)
-        xp_np[:n] = x_np
-        npad = n_v + 2 * v - n
-        xp_np[n:] = -1 - np.arange(npad, dtype=np.int64)
-        (sample_pos, inv_sample, in_D, shifts_np,
-         lam1_np, lam2_np, lam1_jnp, lam2_jnp) = _level_constants(n_v, v)
+        with span("dcv.pack"):
+            xp_np = np.empty(n_v + 2 * v, dtype=np.int64)
+            xp_np[:n] = x_np
+            npad = n_v + 2 * v - n
+            xp_np[n:] = -1 - np.arange(npad, dtype=np.int64)
+            (sample_pos, inv_sample, in_D, shifts_np,
+             lam1_np, lam2_np, lam1_jnp, lam2_jnp) = _level_constants(n_v, v)
+            lo, hi = -npad, int(x_np.max(initial=0))
         m = len(sample_pos)
-        lo, hi = -npad, int(x_np.max(initial=0))
 
         if impl == "bitonic":
-            xp = jnp.asarray(xp_np, jnp.int32)
-            sp_dev = jnp.asarray(sample_pos, jnp.int32)
-            Xp_dev, distinct_dev, sa_rank_dev = _encode_sample(
-                xp, sp_dev, v, m)
-            Xp = np.asarray(Xp_dev).astype(np.int64)
-            distinct = bool(distinct_dev)
-            sa_rank = np.asarray(sa_rank_dev).astype(np.int64)
+            with span("dcv.sort"):
+                xp = jnp.asarray(xp_np, jnp.int32)
+                sp_dev = jnp.asarray(sample_pos, jnp.int32)
+                Xp_dev, distinct_dev, sa_rank_dev = _encode_sample(
+                    xp, sp_dev, v, m)
+                Xp = np.asarray(Xp_dev).astype(np.int64)
+                distinct = bool(distinct_dev)
+                sa_rank = np.asarray(sa_rank_dev).astype(np.int64)
             if not distinct:
                 v_next = schedule(v, len(tabs.D), m)
-                sa_sub = rec(Xp, v_next)
+                sa_sub = rec(Xp, v_next, level + 1)
                 sa_rank = np.zeros(m, dtype=np.int64)
                 sa_rank[sa_sub] = np.arange(m, dtype=np.int64)
-            sa_full = np.asarray(_fused_final_sort(
-                xp, sp_dev, jnp.asarray(sa_rank, jnp.int32),
-                jnp.asarray(tabs.shifts, jnp.int32),
-                lam1_jnp, lam2_jnp, v, n_v))
+            with span("dcv.sort"):
+                sa_full = np.asarray(_fused_final_sort(
+                    xp, sp_dev, jnp.asarray(sa_rank, jnp.int32),
+                    jnp.asarray(tabs.shifts, jnp.int32),
+                    lam1_jnp, lam2_jnp, v, n_v))
             return sa_full[sa_full < n]
 
         # --- keyed paths: ONE window sort feeds Step 1 AND Steps 2–4 ---
@@ -651,38 +674,43 @@ def suffix_array_jax(
 
         # Step 1: sample ranks = the window order filtered to sample
         # positions (a stable subsequence of a sorted sequence is sorted).
-        s_slots = np.flatnonzero(in_D[order % v])
-        sp = order[s_slots]                       # sample pos, window-sorted
-        si = inv_sample[sp]
-        if impl == "pallas" and m > 1:
-            from ..kernels.ops import dense_rank_sorted
-            rows_s = np.stack([c[sp] for c in rep], axis=1)
-            ranks_dev, _ = dense_rank_sorted(
-                jnp.asarray(rows_s, jnp.int32),
-                interpret=not pallas_available())
-            ranks_sorted = np.asarray(ranks_dev).astype(np.int64)
-            distinct = bool(ranks_sorted[-1] == m - 1)
-        else:
-            sb = np.ones(m, dtype=bool)
-            if m > 1:
-                sb[1:] = _rows_neq(rep, sp[1:], sp[:-1])
-            ranks_sorted = np.cumsum(sb) - 1
-            distinct = bool(ranks_sorted[-1] == m - 1)
-        sa_rank = np.empty(m, dtype=np.int64)
-        if distinct:
-            sa_rank[si] = np.arange(m, dtype=np.int64)
-        else:
-            Xp = np.empty(m, dtype=np.int64)
-            Xp[si] = ranks_sorted
+        with span("dcv.rank") as rank_span:
+            s_slots = np.flatnonzero(in_D[order % v])
+            sp = order[s_slots]                   # sample pos, window-sorted
+            si = inv_sample[sp]
+            if impl == "pallas" and m > 1:
+                from ..kernels.ops import dense_rank_sorted
+                rows_s = np.stack([c[sp] for c in rep], axis=1)
+                ranks_dev, _ = dense_rank_sorted(
+                    jnp.asarray(rows_s, jnp.int32),
+                    interpret=not pallas_available())
+                ranks_sorted = np.asarray(ranks_dev).astype(np.int64)
+                distinct = bool(ranks_sorted[-1] == m - 1)
+            else:
+                sb = np.ones(m, dtype=bool)
+                if m > 1:
+                    sb[1:] = _rows_neq(rep, sp[1:], sp[:-1])
+                ranks_sorted = np.cumsum(sb) - 1
+                distinct = bool(ranks_sorted[-1] == m - 1)
+            rank_span.set_metadata(distinct=distinct)
+            sa_rank = np.empty(m, dtype=np.int64)
+            if distinct:
+                sa_rank[si] = np.arange(m, dtype=np.int64)
+            else:
+                Xp = np.empty(m, dtype=np.int64)
+                Xp[si] = ranks_sorted
+        if not distinct:
             v_next = schedule(v, len(tabs.D), m)
-            sa_sub = rec(Xp, v_next)
+            sa_sub = rec(Xp, v_next, level + 1)
             sa_rank[sa_sub] = np.arange(m, dtype=np.int64)
 
         # Steps 2–4: refine the shared window order with Lemma-1 ranks.
-        rank = np.full(n_v + v, -1, dtype=np.int64)
-        rank[sample_pos] = sa_rank
-        sa_full = _resolve_ties(order, is_start, rank, shifts_np,
-                                lam1_np, lam2_np, lam1_jnp, lam2_jnp, v, n_v)
+        with span("dcv.ties"):
+            rank = np.full(n_v + v, -1, dtype=np.int64)
+            rank[sample_pos] = sa_rank
+            sa_full = _resolve_ties(order, is_start, rank, shifts_np,
+                                    lam1_np, lam2_np, lam1_jnp, lam2_jnp, v,
+                                    n_v)
         return sa_full[sa_full < n]
 
     return rec(x.astype(np.int64), v).astype(np.int32)
