@@ -443,9 +443,10 @@ def _lambda_tiebreak_jit(seg, rvals, klass, pos, lam_i1, lam_i2):
     return bitonic_sort(payload, lt_fn)["idx"]
 
 
-#: tie groups wider than this run on the jitted device network; narrower
-#: ones (the overwhelmingly common case) run the same bitonic schedule
-#: lane-parallel in numpy, skipping the device round-trip entirely.
+#: a residue whose widest tie group needs more lanes than this runs on the
+#: jitted device network; otherwise (the overwhelmingly common case) the
+#: same bitonic schedule runs lane-parallel in numpy, each group at its own
+#: power-of-two width, skipping the device round-trip entirely.
 _HOST_LANE_MAX = 16
 
 
@@ -483,6 +484,36 @@ def _lambda_tiebreak_host(p, lane, row_of, n_rows, g2, rvals, klass,
     return p[mat[mat >= 0]]          # row-major: groups in slot order
 
 
+def _lambda_tiebreak_bucketed(p, lane, row_of, n_rows, rvals, klass,
+                              lam1_np, lam2_np) -> tuple[np.ndarray, int]:
+    """`_lambda_tiebreak_host` with each tie group at its own width.
+
+    Groups are bucketed by next_pow2(size) and each width class runs the
+    network alone, so a residue of pairs pays one stage, not the widest
+    group's schedule. The comparator is a total order (index tie-break),
+    so the result is the single-width network's. A class's groups keep
+    their slot order, so its result lands back on its own slots. Returns
+    the permutation and the lanes each stage evaluates, summed over the
+    classes (groups × width)."""
+    sizes = np.bincount(row_of, minlength=n_rows)
+    widest = int(sizes.max())
+    out = np.empty_like(p)
+    slots = 0
+    lo, width = 0, 2
+    while lo < widest:
+        in_class = (sizes > lo) & (sizes <= width)
+        n_class = int(in_class.sum())
+        if n_class:
+            sel = in_class[row_of]
+            class_row = np.cumsum(in_class) - 1
+            out[sel] = _lambda_tiebreak_host(
+                p[sel], lane[sel], class_row[row_of[sel]], n_class, width,
+                rvals[sel], klass[sel], lam1_np, lam2_np)
+            slots += n_class * width
+        lo, width = width, 2 * width
+    return out, slots
+
+
 # --------------------------------------------------------------------------
 # keyed final phase (sort_impl = "radix" / "lax" / "pallas")
 # --------------------------------------------------------------------------
@@ -501,8 +532,9 @@ def _resolve_ties(order, is_start, rank, shifts_np, lam1_np, lam2_np,
     (adversarial inputs), stride-doubling refinement rounds shrink it using
     the group ranks themselves as keys (classic Manber–Myers, seeded at
     resolution v); the residue is resolved by the Lemma-1 comparator on a
-    compacted payload — lane-parallel in numpy for narrow groups, the
-    jitted bitonic network for wide ones.
+    compacted payload — lane-parallel in numpy when no group is wider than
+    `_HOST_LANE_MAX`, each group sorted at its own power-of-two width, else
+    the jitted bitonic network over the whole residue.
     """
     def run_state(is_start):
         start_slot = np.flatnonzero(is_start)
@@ -552,11 +584,11 @@ def _resolve_ties(order, is_start, rank, shifts_np, lam1_np, lam2_np,
     lane = sl - start_slot[run_id[sl]]
     g2 = next_pow2(int(lane.max()) + 1)
     if g2 <= _HOST_LANE_MAX:
-        with span("dcv.lemma1", ties=U, width=g2, path="host"):
+        with span("dcv.lemma1", ties=U, width=g2, path="host") as sp:
             rows, row_of = np.unique(run_id[sl], return_inverse=True)
-            order[sl] = _lambda_tiebreak_host(
-                p, lane, row_of, len(rows), g2, rvals, klass, lam1_np,
-                lam2_np)
+            order[sl], slots = _lambda_tiebreak_bucketed(
+                p, lane, row_of, len(rows), rvals, klass, lam1_np, lam2_np)
+            sp.set_metadata(slots=slots)
         return order
 
     n2 = next_pow2(U)

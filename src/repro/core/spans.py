@@ -43,10 +43,13 @@ Every span and its counters, nested as listed:
 ``sa.dcv.refine`` (``ties``)
     One stride-doubling round, while the tie set is large; ``ties`` rows
     are tied as it starts.
-``sa.dcv.lemma1`` (``ties``, ``width``, ``path``)
-    The Lemma-1 comparator on the residue: ``ties`` rows in groups padded
-    to ``width`` lanes, on the ``host`` (numpy) or the ``device``
-    (jitted network, upload and download included).
+``sa.dcv.lemma1`` (``ties``, ``width``, ``path``, ``slots``)
+    The Lemma-1 comparator on the residue: ``ties`` rows whose widest
+    group needs ``width`` lanes, on the ``host`` (numpy) or the ``device``
+    (jitted network, upload and download included). ``slots``, on the
+    host only: the lanes each stage evaluates, every group padded to its
+    own power-of-two width (``ties / slots`` is the share doing real
+    work).
 ``sa.dcv.base``
     The recursion's base case, a prefix-doubling sort (on the device
     except for ``radix``).

@@ -91,6 +91,34 @@ def test_jax_matches_seq_exactly():
         assert np.array_equal(a, b)
 
 
+@pytest.mark.parametrize("sort_impl", ["radix", "lax"])
+def test_jax_zipf_tokens_mixed_tie_widths(sort_impl, monkeypatch):
+    """Zipf GPT-2-like ids with planted repeats: the host Lemma-1 residue
+    mixes pairs with groups of up to 16, each class sorted at its width."""
+    import repro.core.dcv_jax as dcv_jax
+    rng = np.random.default_rng(15)
+    n = 1 << 15
+    zipf = 1.0 / np.arange(1, 50258)
+    x = rng.choice(50257, size=n, p=zipf / zipf.sum())
+    for copies in (2, 2, 3, 3, 5, 9):
+        span = int(rng.integers(50, 200))
+        src = int(rng.integers(0, n - span))
+        for dst in rng.integers(0, n - span, size=copies - 1):
+            x[dst:dst + span] = x[src:src + span]
+
+    widths = []
+    host = dcv_jax._lambda_tiebreak_host
+
+    def spy(p, lane, row_of, n_rows, g2, *rest):
+        widths.append(g2)
+        return host(p, lane, row_of, n_rows, g2, *rest)
+
+    monkeypatch.setattr(dcv_jax, "_lambda_tiebreak_host", spy)
+    got = suffix_array_jax(x, sort_impl=sort_impl)
+    assert np.array_equal(got, suffix_array_doubling(x))
+    assert {2, 16} <= set(widths)
+
+
 # ----------------------------------------------------------- instrumentation
 def _model_rounds(n, stop, schedule):
     """Recursion depth without the all-distinct early exit (worst case —
